@@ -28,44 +28,32 @@ METRICS_KEYS = {
 }
 SUMMARY_KEYS = {"n", "mean_ms", "p50_ms", "p90_ms", "p99_ms", "max_ms"}
 
-# per-kernel-row benches carry these instead of engine metrics
-ROW_KEYS = {"name", "us_per_call"}
-
-# bench name -> (required top-level keys, key holding the run list/map,
-#                record kind: "engine" = EngineMetrics.as_dict() runs,
-#                "rows" = kernel-benchmark CSV rows)
+# bench name -> (required top-level keys, key holding the run list/map of
+#                EngineMetrics.as_dict() runs)
 SCHEMAS = {
     # serving_load additionally carries the capacity<1.0 overflow-policy
     # sections (DESIGN.md §14): policy throughput gate, balanced-training
     # overflow gate, and the approximate-repair error bound
     "serving_load": ({"bench", "quick", "slots", "classes", "policy_compare",
-                      "balance_compare", "repair_error", "runs"}, "runs",
-                     "engine"),
+                      "balance_compare", "repair_error", "runs"}, "runs"),
     "serving_chunked": ({"bench", "quick", "slots", "chunk",
                          "decode_interval_p99_drop", "stall_bound_tokens",
-                         "runs"}, "runs", "engine"),
+                         "runs"}, "runs"),
     "serving_qos": ({"bench", "quick", "slots", "classes", "fairness",
-                     "profile_convergence", "overflow_decode", "runs"},
-                    "runs", "engine"),
+                     "profile_convergence", "overflow_decode", "runs"}, "runs"),
     "serving_spec": ({"bench", "quick", "slots", "depth", "gen", "spec_k",
                       "classes", "speedup", "speedup_gate", "speedup_ok",
-                      "overflow_ok", "runs"}, "runs", "engine"),
+                      "overflow_ok", "runs"}, "runs"),
     "serving_paged": ({"bench", "quick", "slots", "page_size", "shared_len",
                        "gen", "prefill_ratio", "prefill_gate", "prefill_ok",
                        "ttft_ok", "parity_checked", "compile_ok",
-                       "compiled_shapes", "runs"}, "runs", "engine"),
+                       "compiled_shapes", "runs"}, "runs"),
     "serving_cluster": ({"bench", "quick", "topology", "page_size", "gen",
                          "speedup", "speedup_gate", "speedup_ok", "kill_ok",
                          "lost_requests", "parity_checked", "worker_restarts",
                          "replayed_requests", "duplicate_results", "scale_ok",
                          "scale_events", "compile_ok", "compiled_shapes",
-                         "runs"}, "runs", "engine"),
-    # fused decode megakernel vs the 3-dispatch path (DESIGN.md §13):
-    # kernel timing rows, not engine runs — plus the dispatch contract
-    "roofline": ({"bench", "quick", "dryrun_records", "shape",
-                  "dispatches_fused", "dispatches_baseline", "dispatch_ok",
-                  "speedup", "speedup_ok", "hbm_bytes_per_token", "rows"},
-                 "rows", "rows"),
+                         "runs"}, "runs"),
 }
 
 
@@ -81,7 +69,7 @@ def check_artifact(path: str) -> list:
     if bench not in SCHEMAS:
         return [f"{path}: unknown/missing bench id {bench!r} "
                 f"(known: {sorted(SCHEMAS)})"]
-    required, runs_key, kind = SCHEMAS[bench]
+    required, runs_key = SCHEMAS[bench]
     missing = required - set(doc)
     if missing:
         problems.append(f"{path}: missing top-level keys {sorted(missing)}")
@@ -89,15 +77,11 @@ def check_artifact(path: str) -> list:
     records = list(runs.values()) if isinstance(runs, dict) else list(runs)
     if not records:
         problems.append(f"{path}: empty {runs_key!r}")
-    per_record = METRICS_KEYS if kind == "engine" else ROW_KEYS
     for i, rec in enumerate(records):
-        gone = per_record - set(rec)
+        gone = METRICS_KEYS - set(rec)
         if gone:
-            problems.append(f"{path}: run[{i}] missing "
-                            f"{'metric' if kind == 'engine' else 'row'} "
-                            f"keys {sorted(gone)}")
-            continue
-        if kind != "engine":
+            problems.append(f"{path}: run[{i}] missing metric keys "
+                            f"{sorted(gone)}")
             continue
         for k in ("ttft_ms", "decode_step_ms"):
             if set(rec[k]) != SUMMARY_KEYS:

@@ -6,10 +6,6 @@
   table2 -> paper Table 2 (FFF vs MoE vs FF + epochs-to-train)
   fig34  -> paper Figures 3-4 (mechanism latency scaling, BERT dims)
   table3 -> paper Table 3 (ViT with FFF layers)
-  roofline -> formats the dry-run roofline artifact AND measures the fused
-             decode megakernel vs the 3-dispatch kernel path at decode
-             shape, asserting the one-pallas_call dispatch contract
-             (DESIGN.md §13; writes BENCH_roofline.json)
   ep_dispatch -> grouped_ep dispatch-locality curve: tokens/s, per-shard
                  capacity and bytes moved vs model-shard count, plus the
                  overflow-policy traffic gate (master_leaf repair bytes == 0,
@@ -59,24 +55,23 @@ def main() -> None:
                     help="paper-scale grids (slow)")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: table1,fig2,table2,fig34,"
-                         "table3,roofline,ep_dispatch,serving,"
+                         "table3,ep_dispatch,serving,"
                          "serving_chunked,serving_qos,serving_spec,"
                          "serving_paged,serving_cluster")
     args = ap.parse_args()
 
     from repro import utils
     utils.enable_compile_cache()
-    from benchmarks import (ep_dispatch, fig2, fig34, roofline_bench,
-                            serving_chunked, serving_cluster, serving_load,
-                            serving_paged, serving_qos, serving_spec,
-                            table1, table2, table3)
+    from benchmarks import (ep_dispatch, fig2, fig34, serving_chunked,
+                            serving_cluster, serving_load, serving_paged,
+                            serving_qos, serving_spec, table1, table2,
+                            table3)
     suites = {
         "table1": table1.main,
         "fig2": fig2.main,
         "table2": table2.main,
         "fig34": fig34.main,
         "table3": table3.main,
-        "roofline": roofline_bench.main,
         "ep_dispatch": ep_dispatch.main,
         "serving": serving_load.main,
         "serving_chunked": serving_chunked.main,

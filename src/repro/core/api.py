@@ -204,14 +204,21 @@ class RoutingStats:
     overflow:    scalar — the call's overflow_fraction (0 for exact paths)
     slots:       scalar — total routed (token, tree) slots (weight for
                  averaging overflow across sites)
+    distinct:    scalar — leaves with a non-zero count over the call's valid
+                 rows: the leaves the call read.  For a forest it counts
+                 distinct leaf indices across trees, a lower bound on the
+                 distinct (tree, leaf) pairs read.  Summed over sites like
+                 ``slots`` when a layer scan stacks them.
     """
     leaf_counts: jax.Array
     overflow: jax.Array
     slots: jax.Array
+    distinct: jax.Array
 
 
 jax.tree_util.register_dataclass(
-    RoutingStats, data_fields=["leaf_counts", "overflow", "slots"],
+    RoutingStats, data_fields=["leaf_counts", "overflow", "slots",
+                               "distinct"],
     meta_fields=[])
 
 
@@ -237,13 +244,17 @@ def routing_enabled() -> bool:
     return bool(getattr(_thread_state, "routing", False))
 
 
-def routing_stats_from(out: FFFOutput, cfg: "fff_lib.FFFConfig"
+def routing_stats_from(out: FFFOutput, cfg: "fff_lib.FFFConfig",
+                       valid: Optional[jax.Array] = None
                        ) -> Optional[RoutingStats]:
     """Compact per-call telemetry from a backend's ``FFFOutput``.
 
     Reduces ``leaf_idx`` (B, ..., trees) to a per-batch-row leaf histogram
     (B, E); returns None when the backend reported no leaf indices (e.g.
-    FORWARD_T training, depth-0 sites)."""
+    FORWARD_T training, depth-0 sites).  ``valid`` (broadcastable to the
+    call's token shape, ``leaf_idx.shape[:-1]``) marks the tokens whose
+    leaves ``distinct`` counts: exact backends report phantom rows' real
+    leaves, which stay in ``leaf_counts`` as before."""
     if out.leaf_idx is None:
         return None
     idx = out.leaf_idx
@@ -255,8 +266,14 @@ def routing_stats_from(out: FFFOutput, cfg: "fff_lib.FFFConfig"
     counts = counts.astype(jnp.float32)
     ovf = (out.overflow_fraction if out.overflow_fraction is not None
            else jnp.zeros((), jnp.float32))
+    read = idx
+    if valid is not None:                  # the sentinel id is not counted
+        read = jnp.where(jnp.broadcast_to(valid, idx.shape[:-1])[..., None],
+                         idx, cfg.num_leaves)
+    distinct = (jnp.bincount(read.reshape(-1), length=cfg.num_leaves) > 0)
     return RoutingStats(leaf_counts=counts, overflow=ovf,
-                        slots=counts.sum())
+                        slots=counts.sum(),
+                        distinct=distinct.sum().astype(jnp.float32))
 
 BackendFn = Callable[[dict, "fff_lib.FFFConfig", jax.Array, ExecutionSpec],
                      tuple[jax.Array, FFFOutput]]

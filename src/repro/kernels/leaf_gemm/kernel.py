@@ -91,6 +91,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((E, C, H), out_dtype),
         interpret=interpret,
+        name="leaf_gemm",
     )(group_sizes, x, w)
 
 
@@ -157,4 +158,5 @@ def grouped_matmul_dual(x: jax.Array, wg: jax.Array, wu: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((E, C, H), out_dtype),
         interpret=interpret,
+        name="leaf_gemm_dual",
     )(group_sizes, x, wg, wu)

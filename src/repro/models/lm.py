@@ -96,9 +96,10 @@ def _embed_inputs(params: Params, cfg: ModelConfig, batch: dict,
 
 
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = norms.norm_apply(cfg.norm, params["final_norm"], x)
-    lg = embeddings.logits(params["embed"], x)
-    return act.shard(lg, act.LOGITS_BSV)
+    with jax.named_scope("lm_head"):
+        x = norms.norm_apply(cfg.norm, params["final_norm"], x)
+        lg = embeddings.logits(params["embed"], x)
+        return act.shard(lg, act.LOGITS_BSV)
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,

@@ -16,6 +16,7 @@ Design notes (DESIGN.md §5):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, NamedTuple, Optional
 
@@ -411,6 +412,17 @@ def chunk_attend(q: jax.Array, cache: KVCache, start: jax.Array, *,
 # block-level entry points
 # ---------------------------------------------------------------------------
 
+def _scoped(fn):
+    """Trace ``fn`` under the ``attention`` name scope: its ops carry the
+    name in their HLO metadata (the device trace's per-scope time)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("attention"):
+            return fn(*args, **kwargs)
+    return scoped
+
+
+@_scoped
 def forward(params: Params, cfg: AttnConfig, x: jax.Array,
             positions: Optional[jax.Array] = None,
             use_flash_above: int = 2048) -> jax.Array:
@@ -431,6 +443,7 @@ def forward(params: Params, cfg: AttnConfig, x: jax.Array,
     return out_proj(params, cfg, o)
 
 
+@_scoped
 def forward_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
                     cache: KVCache, use_flash_above: int = 2048
                     ) -> tuple[jax.Array, KVCache]:
@@ -446,6 +459,7 @@ def forward_prefill(params: Params, cfg: AttnConfig, x: jax.Array,
     return out_proj(params, cfg, o), cache
 
 
+@_scoped
 def forward_decode(params: Params, cfg: AttnConfig, x1: jax.Array,
                    cache: KVCache, write_mask: Optional[jax.Array] = None
                    ) -> tuple[jax.Array, KVCache]:
@@ -459,6 +473,7 @@ def forward_decode(params: Params, cfg: AttnConfig, x1: jax.Array,
     return out_proj(params, cfg, o), cache
 
 
+@_scoped
 def forward_chunk(params: Params, cfg: AttnConfig, x: jax.Array,
                   cache: KVCache, valid_len: jax.Array
                   ) -> tuple[jax.Array, KVCache]:
@@ -479,6 +494,7 @@ def forward_chunk(params: Params, cfg: AttnConfig, x: jax.Array,
     return out_proj(params, cfg, o), cache
 
 
+@_scoped
 def forward_cross(params: Params, cfg: AttnConfig, x: jax.Array,
                   enc_k: jax.Array, enc_v: jax.Array) -> jax.Array:
     """Cross-attention: queries from x (B, S, D), precomputed encoder K/V."""

@@ -83,9 +83,11 @@ def forward(params: Params, spec: FFNSpec, d_model: int, x: jax.Array, *,
         cfg = make_fff_config(spec, d_model, **kw)
         # one entry point; backend="auto" picks the execution strategy per
         # platform/site (and the launch layer can steer it via
-        # api.overrides) — see core/api.py
-        y, out = api.apply(params, cfg, x, api.ExecutionSpec(
-            mode="train" if train else "infer", rng=rng, valid=valid))
+        # api.overrides) — see core/api.py.  The scope names the site's ops
+        # in the HLO metadata (the device trace's per-scope time)
+        with jax.named_scope("fff_site"):
+            y, out = api.apply(params, cfg, x, api.ExecutionSpec(
+                mode="train" if train else "infer", rng=rng, valid=valid))
         if train:
             harden = spec.hardening_scale * fff.hardening_loss(out.node_probs)
             # load-balancing over soft leaf usage (DESIGN.md §14); the soft
@@ -99,7 +101,7 @@ def forward(params: Params, spec: FFNSpec, d_model: int, x: jax.Array, *,
         if not train and api.routing_enabled():
             # serving telemetry rides the aux return (DESIGN.md §9): a side
             # list would capture scan-body tracers under scan_layers
-            aux["routing"] = api.routing_stats_from(out, cfg)
+            aux["routing"] = api.routing_stats_from(out, cfg, valid)
         return y, aux
     if spec.kind == "moe":
         cfg = make_moe_config(spec, d_model, **kw)

@@ -30,14 +30,16 @@ def _routing_weighted(r: "api.RoutingStats | None"):
     across the period scan (finalized back to a fraction below)."""
     if r is None:
         return None
-    return api.RoutingStats(r.leaf_counts, r.overflow * r.slots, r.slots)
+    return api.RoutingStats(r.leaf_counts, r.overflow * r.slots, r.slots,
+                            r.distinct)
 
 
 def _routing_finalize(r: "api.RoutingStats | None"):
     if r is None:
         return None
     return api.RoutingStats(r.leaf_counts,
-                            r.overflow / jnp.maximum(r.slots, 1.0), r.slots)
+                            r.overflow / jnp.maximum(r.slots, 1.0), r.slots,
+                            r.distinct)
 
 
 # ---------------------------------------------------------------------------

@@ -75,6 +75,7 @@ from repro.models import lm
 from repro.serving import metrics as metrics_lib
 from repro.serving import paging
 from repro.serving import spec as spec_lib
+from repro.serving import tracing
 from repro.serving.profiles import RoutingProfileStore
 from repro.serving.request import Request, RequestResult, SlotState
 from repro.serving.scheduler import Scheduler, SchedulerView, make_scheduler
@@ -439,13 +440,16 @@ class ContinuousBatchingEngine:
         self._arrivals: Dict[int, float] = {}   # id(req) -> engine-clock s
 
         # every cache-threading entry point goes through _jit: it donates
-        # the pooled caches and keeps their pinned layout (see there)
-        self._decode_jit = self._jit(
-            lambda p, t, c, off, wm, lv: lm.decode_step(p, cfg, t, c, off,
-                                                        write_mask=wm,
-                                                        token_valid=lv,
-                                                        with_stats=True),
-            (2,), (None, "target", None))
+        # the pooled caches and keeps their pinned layout (see there).  Each
+        # is a function of this engine's own (jit's caches are keyed by the
+        # function, so a shared one would count other engines' shapes in
+        # compiled_shapes), named for the profiler's host events
+        def decode_step(p, t, c, off, wm, lv):
+            return lm.decode_step(p, cfg, t, c, off, write_mask=wm,
+                                  token_valid=lv, with_stats=True)
+
+        self._decode_jit = self._jit(decode_step, (2,),
+                                     (None, "target", None))
         if self.spec:
             dcfg = self.draft_cfg
             # every spec-mode entry point that touches caches touches BOTH
@@ -456,25 +460,27 @@ class ContinuousBatchingEngine:
             # slab at bucket width: only the admitted row is valid, and its
             # offset starts at the shared-prefix boundary (DESIGN.md §11).
             def chunk_both():          # a fresh function per compiled shape
-                return self._jit(
-                    lambda p, dp, t, v, c, dc, off: spec_lib.chunk_both(
-                        p, cfg, dp, dcfg, t, v, c, dc, off),
-                    (4, 5), (None, "target", "draft", None, None))
+                def prefill_chunk_both(p, dp, t, v, c, dc, off):
+                    return spec_lib.chunk_both(p, cfg, dp, dcfg, t, v, c, dc,
+                                               off)
+                return self._jit(prefill_chunk_both, (4, 5),
+                                 (None, "target", "draft", None, None))
             self._prefill_jits = {b: chunk_both() for b in ecfg.buckets()}
             self._chunk_jit = chunk_both() if ecfg.prefill_chunk else None
-            self._admit_jit = self._jit(
-                lambda c, dc, ad, tb, ln, cs, cd: (
-                    lm.cache_admit(c, ad, tb, ln, cs, cd),
-                    lm.cache_admit(dc, ad, tb, ln, cs, cd)),
-                (0, 1), ("target", "draft"))
+            def cache_admit_both(c, dc, ad, tb, ln, cs, cd):
+                return (lm.cache_admit(c, ad, tb, ln, cs, cd),
+                        lm.cache_admit(dc, ad, tb, ln, cs, cd))
+
+            self._admit_jit = self._jit(cache_admit_both, (0, 1),
+                                        ("target", "draft"))
             # the whole round — both trees' length rollback, k+1 scanned
             # draft decode steps with on-device sampling, and the target's
             # (num_slots, k+1) verify — in one compiled shape.  The
             # per-round PRNG key derives inside the trace from a traced
             # round counter, so the jit compiles once.
-            self._spec_jit = self._jit(
-                lambda p, dp, t0, c, dc, tl, dl, p0, wm, vl, lv, tp, rnd:
-                spec_lib.spec_round(
+            def spec_round(p, dp, t0, c, dc, tl, dl, p0, wm, vl, lv, tp,
+                           rnd):
+                return spec_lib.spec_round(
                     p, cfg, dp, dcfg, t0, c, dc, tl, dl, p0, wm, vl, lv, tp,
                     jax.random.fold_in(jax.random.PRNGKey(ecfg.seed), rnd),
                     verify_cf=self._verify_cf(),
@@ -482,19 +488,23 @@ class ContinuousBatchingEngine:
                     # the megakernel's shape; the verify slab is not and
                     # keeps the normal resolution (DESIGN.md §13)
                     draft_backend=("pallas_decode" if ecfg.pallas_decode
-                                   else None)),
-                (3, 4), (None, None, None, "target", "draft", None, None))
+                                   else None))
+
+            self._spec_jit = self._jit(
+                spec_round, (3, 4),
+                (None, None, None, "target", "draft", None, None))
         else:
             def chunk():               # a fresh function per compiled shape
-                return self._jit(
-                    lambda p, t, v, c, off: lm.prefill_chunk(p, cfg, t, v, c,
-                                                             off),
-                    (3,), (None, "target", None))
+                def prefill_chunk(p, t, v, c, off):
+                    return lm.prefill_chunk(p, cfg, t, v, c, off)
+                return self._jit(prefill_chunk, (3,), (None, "target", None))
             self._prefill_jits = {b: chunk() for b in ecfg.buckets()}
             self._chunk_jit = chunk() if ecfg.prefill_chunk else None
-            self._admit_jit = self._jit(
-                lambda c, ad, tb, ln, cs, cd: lm.cache_admit(
-                    c, ad, tb, ln, cs, cd), (0,), "target")
+
+            def cache_admit(c, ad, tb, ln, cs, cd):
+                return lm.cache_admit(c, ad, tb, ln, cs, cd)
+
+            self._admit_jit = self._jit(cache_admit, (0,), "target")
         # per-slot raw leaf counts accumulated across a request's prefill
         # chunks; normalized into self.occupancy when its prefill completes
         self._prefill_counts = np.zeros((S, max(self.num_leaves, 1)),
@@ -515,6 +525,12 @@ class ContinuousBatchingEngine:
         # prefill lands in one of these gaps; chunked prefill bounds them)
         self.decode_interval_s: List[float] = []
         self._last_decode_end: Optional[float] = None
+        # the counters of serving/tracing.py: this engine's id in their log,
+        # the layer scan's period count (RoutingStats.distinct sums over
+        # it), and the running sum and count of fff.decode_leaves samples
+        self._trace_id = tracing.new_engine_id()
+        self._n_periods = cfg.n_layers // len(cfg.period)
+        self._leaves = [0.0, 0]
         # slot-weighted overflow accumulators, split by phase: admission
         # composes the *decode* batch, so decode overflow is the scheduler's
         # signal (spec verify dispatches land there too — they ARE the
@@ -754,6 +770,22 @@ class ContinuousBatchingEngine:
             if c.shape[-1] == self.num_leaves:
                 counts = c if counts is None else counts + c
         return counts
+
+    def _count_decode_leaves(self, stats) -> None:
+        """Record ``fff.decode_leaves`` for one decode call: the distinct
+        leaves each FFF site of the telemetry width read, the mean over
+        those sites (``RoutingStats.distinct`` is summed over the layer
+        scan's periods)."""
+        if stats is None or self.num_leaves == 0:
+            return
+        got = [float(s.distinct) for s in stats if s is not None
+               and s.leaf_counts.shape[-1] == self.num_leaves]
+        if not got:
+            return
+        v = sum(got) / (len(got) * self._n_periods)
+        self._leaves[0] += v
+        self._leaves[1] += 1
+        tracing.record(self.now(), self._trace_id, tracing.DECODE_LEAVES, v)
 
     def _update_occupancy(self, slot_rows: Sequence[int],
                           counts: Optional[np.ndarray],
@@ -1102,58 +1134,67 @@ class ContinuousBatchingEngine:
                       if s is not None and s.prefilling]
         if not prefilling:
             return
-        S, C = self.ecfg.num_slots, self.ecfg.prefill_chunk
-        # inactive rows carry in-distribution filler (same rationale as the
-        # free-slot decode token); their writes are masked out by valid=0
-        toks = np.repeat(self._free_tok[:, None], C, axis=1)
-        valid = np.zeros((S,), np.int32)
-        offs = np.zeros((S,), np.int32)
-        for i in prefilling:
-            st = self.slots[i]
-            p = st.request.prompt
-            n = min(C, len(p) - st.prefill_pos)
-            toks[i, :n] = p[st.prefill_pos:st.prefill_pos + n]
-            toks[i, n:] = p[st.prefill_pos + n - 1]   # pad: last real token
-            valid[i] = n
-            offs[i] = st.prefill_pos
-        with self._ctx():
-            if self.spec:
-                # one slab dispatch advances every prefill in BOTH trees
-                logits, self.caches, self.draft_caches, stats, dstats = \
-                    self._chunk_jit(
-                        self.params, self.draft_params, jnp.asarray(toks),
-                        jnp.asarray(valid), self.caches, self.draft_caches,
-                        jnp.asarray(offs))
-                self._stats_rows(dstats, "draft")
-            else:
-                logits, self.caches, stats = self._chunk_jit(
-                    self.params, jnp.asarray(toks), jnp.asarray(valid),
-                    self.caches, jnp.asarray(offs))
-        logits = np.asarray(jax.block_until_ready(logits))
-        self.n_chunks += 1
-        # slab overflow self-weights by real-token count now: the chunk-mode
-        # validity mask routes filler positions to the sentinel leaf, so
-        # RoutingStats.slots already counts only the valid prompt tokens
-        counts = self._stats_rows(stats, "prefill")
-        for i in prefilling:
-            st = self.slots[i]
-            st.prefill_pos += int(valid[i])
-            self.n_prefill_tokens += int(valid[i])
-            if self.spec:
-                self._tlen[i] += int(valid[i])
-                self._dlen[i] += int(valid[i])
-            if counts is not None:
-                self._prefill_counts[i] += counts[i]
-            if not st.prefilling:          # prompt fully consumed this chunk
-                self.n_prefills += 1
-                tot = self._prefill_counts[i].sum()
-                if tot > 0:
-                    self.occupancy[i] = self._prefill_counts[i] / tot
-                    self._measured[i] = True
-                st.total_len = len(st.request.prompt)
-                st.first_token_time = self.now()
-                self._record_token(st, self._sample(st, logits[i]))
-                self._publish_prefix(i)
+        sp = tracing.CHUNK
+        with tracing.span(sp.name):
+            S, C = self.ecfg.num_slots, self.ecfg.prefill_chunk
+            # inactive rows carry in-distribution filler (same rationale as
+            # the free-slot decode token); valid=0 masks their writes out
+            toks = np.repeat(self._free_tok[:, None], C, axis=1)
+            valid = np.zeros((S,), np.int32)
+            offs = np.zeros((S,), np.int32)
+            for i in prefilling:
+                st = self.slots[i]
+                p = st.request.prompt
+                n = min(C, len(p) - st.prefill_pos)
+                toks[i, :n] = p[st.prefill_pos:st.prefill_pos + n]
+                toks[i, n:] = p[st.prefill_pos + n - 1]   # pad: last real
+                valid[i] = n
+                offs[i] = st.prefill_pos
+            with tracing.span(sp.dispatch), self._ctx():
+                if self.spec:
+                    # one slab dispatch advances every prefill in BOTH trees
+                    logits, self.caches, self.draft_caches, stats, dstats = \
+                        self._chunk_jit(
+                            self.params, self.draft_params,
+                            jnp.asarray(toks), jnp.asarray(valid),
+                            self.caches, self.draft_caches,
+                            jnp.asarray(offs))
+                else:
+                    logits, self.caches, stats = self._chunk_jit(
+                        self.params, jnp.asarray(toks), jnp.asarray(valid),
+                        self.caches, jnp.asarray(offs))
+            with tracing.span(sp.wait):
+                jax.block_until_ready(logits)
+            with tracing.span(sp.to_host):
+                logits = np.asarray(logits)
+            self.n_chunks += 1
+            with tracing.span(sp.stats):
+                if self.spec:
+                    self._stats_rows(dstats, "draft")
+                # slab overflow self-weights by real-token count: the chunk
+                # validity mask routes filler positions to the sentinel
+                # leaf, so RoutingStats.slots counts only valid prompt tokens
+                counts = self._stats_rows(stats, "prefill")
+            with tracing.span(sp.sample):
+                for i in prefilling:
+                    st = self.slots[i]
+                    st.prefill_pos += int(valid[i])
+                    self.n_prefill_tokens += int(valid[i])
+                    if self.spec:
+                        self._tlen[i] += int(valid[i])
+                        self._dlen[i] += int(valid[i])
+                    if counts is not None:
+                        self._prefill_counts[i] += counts[i]
+                    if not st.prefilling:    # prompt fully consumed
+                        self.n_prefills += 1
+                        tot = self._prefill_counts[i].sum()
+                        if tot > 0:
+                            self.occupancy[i] = self._prefill_counts[i] / tot
+                            self._measured[i] = True
+                        st.total_len = len(st.request.prompt)
+                        st.first_token_time = self.now()
+                        self._record_token(st, self._sample(st, logits[i]))
+                        self._publish_prefix(i)
 
     def _decode(self) -> None:
         live = [i for i, s in enumerate(self.slots)
@@ -1181,22 +1222,32 @@ class ContinuousBatchingEngine:
         # deliberately separate from wm, which guards KV writes)
         lv = np.zeros((self.ecfg.num_slots,), bool)
         lv[live] = True
-        t0 = self._clock()
-        with self._ctx(), self._decode_backend_ctx():
-            logits, self.caches, stats = self._decode_jit(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(offs), jnp.asarray(wm), jnp.asarray(lv))
-        logits = np.asarray(jax.block_until_ready(logits))
-        t1 = self._clock()
-        self.decode_lat.append(t1 - t0)
-        if self._last_decode_end is not None:
-            self.decode_interval_s.append(t1 - self._last_decode_end)
-        self._last_decode_end = t1
-        self.n_steps += 1
-        self._update_occupancy(live, self._stats_rows(stats, "decode"))
-        for i in live:
-            self._record_token(self.slots[i], self._sample(self.slots[i],
-                                                           logits[i]))
+        sp = tracing.DECODE
+        with tracing.span(sp.name):
+            t0 = self._clock()
+            with tracing.span(sp.dispatch), self._ctx(), \
+                    self._decode_backend_ctx():
+                logits, self.caches, stats = self._decode_jit(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.asarray(offs), jnp.asarray(wm), jnp.asarray(lv))
+            with tracing.span(sp.wait):
+                jax.block_until_ready(logits)
+            with tracing.span(sp.to_host):
+                logits = np.asarray(logits)
+            t1 = self._clock()
+            self.decode_lat.append(t1 - t0)
+            if self._last_decode_end is not None:
+                self.decode_interval_s.append(t1 - self._last_decode_end)
+            self._last_decode_end = t1
+            self.n_steps += 1
+            with tracing.span(sp.stats):
+                self._update_occupancy(live,
+                                       self._stats_rows(stats, "decode"))
+                self._count_decode_leaves(stats)
+            with tracing.span(sp.sample):
+                for i in live:
+                    self._record_token(self.slots[i],
+                                       self._sample(self.slots[i], logits[i]))
 
     def _spec_round(self) -> None:
         """One speculative draft/verify round (DESIGN.md §10), replacing
@@ -1247,33 +1298,51 @@ class ContinuousBatchingEngine:
         # unverified — vlen clips the verify slab identically)
         wm = lv[None, :] & ((pos0[None, :] + np.arange(k + 1)[:, None])
                             < self._alloc_len[None, :])
-        t0 = self._clock()
-        with self._ctx():
-            (drafts, q_logits, p_logits, self.caches, self.draft_caches,
-             dstats, vstats) = self._spec_jit(
-                self.params, self.draft_params, jnp.asarray(toks),
-                self.caches, self.draft_caches, jnp.asarray(self._tlen),
-                jnp.asarray(self._dlen), jnp.asarray(pos0), jnp.asarray(wm),
-                jnp.asarray(vlen), jnp.asarray(lv), jnp.asarray(temps),
-                jnp.int32(self._spec_rounds))
-        self._spec_rounds += 1
-        p_logits = np.asarray(jax.block_until_ready(p_logits))  # (S,k+1,V)
-        drafts = np.asarray(drafts)                             # (k, S)
-        q_logits = np.asarray(q_logits)                         # (k+1,S,V)
-        # draft leaf histograms: the verify step's occupancy PRIOR.  Width-
-        # mismatched drafts contribute overflow telemetry only (_stats_rows
-        # drops their counts); self-drafts share the target's leaf space.
-        self._update_occupancy(live, self._stats_rows(dstats, "draft"),
-                               measured=False)
-        t1 = self._clock()
-        self.decode_lat.append(t1 - t0)
-        if self._last_decode_end is not None:
-            self.decode_interval_s.append(t1 - self._last_decode_end)
-        self._last_decode_end = t1
-        self.n_steps += 1
-        # verify IS the target's decode: same phase, measured occupancy
-        self._update_occupancy(live, self._stats_rows(vstats, "decode"))
+        sp = tracing.SPEC
+        with tracing.span(sp.name):
+            t0 = self._clock()
+            with tracing.span(sp.dispatch), self._ctx():
+                (drafts, q_logits, p_logits, self.caches, self.draft_caches,
+                 dstats, vstats) = self._spec_jit(
+                    self.params, self.draft_params, jnp.asarray(toks),
+                    self.caches, self.draft_caches, jnp.asarray(self._tlen),
+                    jnp.asarray(self._dlen), jnp.asarray(pos0),
+                    jnp.asarray(wm), jnp.asarray(vlen), jnp.asarray(lv),
+                    jnp.asarray(temps), jnp.int32(self._spec_rounds))
+            self._spec_rounds += 1
+            with tracing.span(sp.wait):
+                jax.block_until_ready(p_logits)
+            with tracing.span(sp.to_host):
+                p_logits = np.asarray(p_logits)                 # (S,k+1,V)
+                drafts = np.asarray(drafts)                     # (k, S)
+                q_logits = np.asarray(q_logits)                 # (k+1,S,V)
+            t1 = self._clock()
+            self.decode_lat.append(t1 - t0)
+            if self._last_decode_end is not None:
+                self.decode_interval_s.append(t1 - self._last_decode_end)
+            self._last_decode_end = t1
+            self.n_steps += 1
+            with tracing.span(sp.stats):
+                # draft leaf histograms: the verify step's occupancy PRIOR.
+                # Width-mismatched drafts contribute overflow telemetry only
+                # (_stats_rows drops their counts); self-drafts share the
+                # target's leaf space.
+                self._update_occupancy(live,
+                                       self._stats_rows(dstats, "draft"),
+                                       measured=False)
+                # verify IS the target's decode: same phase, measured
+                # occupancy
+                self._update_occupancy(live,
+                                       self._stats_rows(vstats, "decode"))
+                self._count_decode_leaves(vstats)
+            with tracing.span(sp.sample):
+                self._accept_drafts(live, vlen, pos0, p_logits, q_logits,
+                                    drafts)
 
+    def _accept_drafts(self, live, vlen, pos0, p_logits, q_logits,
+                       drafts) -> None:
+        """Host-side rejection sampling of one round: each live slot emits
+        its accepted drafts plus the corrected or bonus token."""
         for i in live:
             st = self.slots[i]
             vl = int(vlen[i])
@@ -1308,16 +1377,23 @@ class ContinuousBatchingEngine:
         advance chunked prefills (up to ``prefill_budget`` slab dispatches),
         then decode every active non-prefilling slot together — one plain
         decode step, or one speculative draft/verify round when spec_k >
-        0."""
-        self._evict_finished()
-        self._admit()
-        if self.ecfg.prefill_chunk:
-            for _ in range(self.ecfg.prefill_budget):
-                self._chunk_prefill()
-        if self.spec:
-            self._spec_round()
-        else:
-            self._decode()
+        0.  Each part runs in its span of ``serving/tracing.py``; the step
+        records one ``engine.recompiles`` sample."""
+        with tracing.span(tracing.STEP):
+            n0 = sum(self.recompiles().values())
+            with tracing.span(tracing.EVICT):
+                self._evict_finished()
+            with tracing.span(tracing.ADMIT):
+                self._admit()
+            if self.ecfg.prefill_chunk:
+                for _ in range(self.ecfg.prefill_budget):
+                    self._chunk_prefill()
+            if self.spec:
+                self._spec_round()
+            else:
+                self._decode()
+            tracing.record(self.now(), self._trace_id, tracing.RECOMPILES,
+                           max(sum(self.recompiles().values()) - n0, 0))
 
     def has_work(self) -> bool:
         """True while anything is queued or occupying a slot (the manual
@@ -1347,6 +1423,7 @@ class ContinuousBatchingEngine:
         phit0, cow0 = self.n_prefix_hit_tokens, self.n_cow_copies
         ptoks0 = self.n_prefill_tokens
         ovf0 = {k: list(v) for k, v in self._overflow.items()}
+        leaves0, rec0 = list(self._leaves), self.recompiles()
         t_start = self.now()
         self._last_decode_end = None    # decode gaps don't span runs
         while pending or self.has_work():
@@ -1402,7 +1479,11 @@ class ContinuousBatchingEngine:
             prefix_hit_tokens=self.n_prefix_hit_tokens - phit0,
             cow_copies=self.n_cow_copies - cow0,
             pages_in_use=self.pool.pages_in_use,
-            pages_free=self.pool.pages_free)
+            pages_free=self.pool.pages_free,
+            decode_leaves_mean=((self._leaves[0] - leaves0[0])
+                                / max(self._leaves[1] - leaves0[1], 1)),
+            recompiles={k: n - rec0.get(k, 0)
+                        for k, n in self.recompiles().items()})
         return results, m
 
     def poll_metrics(self) -> metrics_lib.EngineMetrics:
@@ -1432,7 +1513,9 @@ class ContinuousBatchingEngine:
             prefix_hit_tokens=self.n_prefix_hit_tokens,
             cow_copies=self.n_cow_copies,
             pages_in_use=self.pool.pages_in_use,
-            pages_free=self.pool.pages_free)
+            pages_free=self.pool.pages_free,
+            decode_leaves_mean=self._leaves[0] / max(self._leaves[1], 1),
+            recompiles=self.recompiles())
         m.queue_depth = len(self.queue)
         m.active_slots = sum(s is not None for s in self.slots)
         m.prefilling_slots = sum(s is not None and s.prefilling
@@ -1459,6 +1542,11 @@ class ContinuousBatchingEngine:
         return self.occupancy[act].mean(axis=0)
 
     # -- fixed-shape accounting ----------------------------------------------
+
+    def recompiles(self) -> Dict[str, int]:
+        """Traces beyond the first, per entry point of ``compiled_shapes``:
+        all 0 while the fixed-shape contract holds."""
+        return {k: max(n - 1, 0) for k, n in self.compiled_shapes().items()}
 
     def compiled_shapes(self) -> Dict[str, int]:
         """Number of compiled traces per entry point (the fixed-shape

@@ -104,6 +104,12 @@ class EngineMetrics:
     cow_copies: int = 0
     pages_in_use: int = 0
     pages_free: int = 0
+    # serving/tracing.py's counters: distinct leaves per FFF site per
+    # decode call (mean over decode calls), and traces beyond each entry
+    # point's first (entry point -> count; all 0 under the fixed-shape
+    # contract)
+    decode_leaves_mean: float = 0.0
+    recompiles: Dict[str, int] = dataclasses.field(default_factory=dict)
     queue_depth: int = 0                 # waiting requests (instantaneous)
     active_slots: int = 0                # occupied slots (instantaneous)
     prefilling_slots: int = 0            # slots mid-chunked-prefill
@@ -148,6 +154,11 @@ class EngineMetrics:
                 f"{self.accepted_tokens} accepted "
                 f"(acceptance {self.spec_acceptance:.3f}, "
                 f"{self.wasted_tokens} wasted)")
+        if self.decode_leaves_mean:
+            lines.append(f"fff distinct leaves per site per decode call: "
+                         f"mean {self.decode_leaves_mean:.2f}")
+        if any(self.recompiles.values()):
+            lines.append(f"recompiles after warm-up: {self.recompiles}")
         if self.hint_mismatches:
             lines.append(f"leaf_hint size mismatches dropped: "
                          f"{self.hint_mismatches}")
@@ -193,6 +204,8 @@ class EngineMetrics:
             "cow_copies": self.cow_copies,
             "pages_in_use": self.pages_in_use,
             "pages_free": self.pages_free,
+            "decode_leaves_mean": self.decode_leaves_mean,
+            "recompiles": self.recompiles,
             "queue_depth": self.queue_depth,
             "active_slots": self.active_slots,
             "prefilling_slots": self.prefilling_slots,
@@ -240,7 +253,9 @@ def from_results(results: Iterable, *, elapsed_s: float, n_steps: int,
                  prefix_hit_tokens: int = 0,
                  cow_copies: int = 0,
                  pages_in_use: int = 0,
-                 pages_free: int = 0) -> EngineMetrics:
+                 pages_free: int = 0,
+                 decode_leaves_mean: float = 0.0,
+                 recompiles: Dict[str, int] | None = None) -> EngineMetrics:
     """Build an ``EngineMetrics`` from finished ``RequestResult`` records."""
     rs = list(results)
     return EngineMetrics(
@@ -265,4 +280,6 @@ def from_results(results: Iterable, *, elapsed_s: float, n_steps: int,
         cow_copies=cow_copies,
         pages_in_use=pages_in_use,
         pages_free=pages_free,
+        decode_leaves_mean=decode_leaves_mean,
+        recompiles=dict(recompiles or {}),
         tenants=tenant_breakdown(rs, elapsed_s))

@@ -117,7 +117,7 @@ def build_draft(spec: Optional[str], params: Params, cfg,
 def _agg_stats(stats):
     """Collapse scan-stacked per-site RoutingStats (leading k+1 step axis)
     into one per-site aggregate: summed leaf counts / slots, slot-weighted
-    overflow."""
+    overflow, distinct leaves per step (the mean over the steps)."""
     if stats is None:
         return None
     out = []
@@ -129,7 +129,7 @@ def _agg_stats(stats):
         out.append(api.RoutingStats(
             leaf_counts=s.leaf_counts.sum(0),
             overflow=(s.overflow * s.slots).sum() / jnp.maximum(slots, 1.0),
-            slots=slots))
+            slots=slots, distinct=s.distinct.mean(0)))
     return tuple(out)
 
 
