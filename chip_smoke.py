@@ -8,12 +8,13 @@ One chip (the default)
     random weights from ``--seed``.  ``ContinuousBatchingEngine`` serves it
     with ``fff_backend="auto"``: 8 slots, 16 requests with prompts of
     128-1024 tokens and 32 generated tokens each, chunked prefill of 256
-    tokens (so the compiled family is decode, one chunk slab, admit).  Then
-    every FFF kernel path the run used is compared, on the chip and at full
-    width, against ``backend="reference"`` in float32 on the same site
-    weights and inputs: at the decode shape, on a 64-token prefill slab,
-    and on a skewed 256-token slab at a low capacity factor, where the
-    kernel's exact overflow repair must serve the tokens it dropped.
+    tokens (so the compiled family is decode, a chunk slab per row bucket,
+    admit).  Then every FFF kernel path the run used is compared, on
+    the chip and at full width, against ``backend="reference"`` in float32
+    on the same site weights and inputs: at the decode shape, on a 64-token
+    prefill slab, and on a skewed 256-token slab at a low capacity factor,
+    where the kernel's exact overflow repair must serve the tokens it
+    dropped.
 
 Four chips (``--four-chips``)
     The whole 48-layer model (about 40 GB of bf16 weights: it needs four

@@ -17,8 +17,8 @@ exactly one role:
   spec_round) plus the single ``install`` dispatch.
 
 The per-role split is what keeps the per-worker compile contract at the
-single-engine counts (decode 1 / chunk slab 1 / spec_round 1 / admit 1):
-disaggregation adds processes, not compiled programs.
+single-engine counts (decode 1 / each chunk row bucket 1 / spec_round 1
+/ admit 1): disaggregation adds processes, not compiled programs.
 
 ``worker_main`` is the ProcBus child entrypoint: module-level (picklable
 by the spawn context), rebuilds params from ``(cfg, seed)`` — bit-exact,

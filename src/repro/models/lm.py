@@ -314,27 +314,39 @@ def cache_install(caches: list[dict], admit: jax.Array, tables: jax.Array,
 
 def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
                   valid_len: jax.Array, caches: list[dict],
-                  pos_offset: jax.Array) -> tuple[jax.Array, list[dict], Any]:
-    """Consume one chunk of prefill for every row of a pooled cache at once
+                  pos_offset: jax.Array, rows: Optional[jax.Array] = None
+                  ) -> tuple[jax.Array, list[dict], Any]:
+    """Consume one chunk of prefill for many rows of a pooled cache at once
     (chunked prefill, DESIGN.md §9).
 
-    ``tokens`` is a fixed-shape (B, C) slab — B = num_slots, C = the engine's
-    ``prefill_chunk`` — so ALL in-flight prefills advance in ONE dispatch
-    that compiles exactly once.  ``valid_len`` (B,) int32 in [0, C] is each
-    row's real token count this chunk (0 = the slot has no prefill work;
-    its slab row is in-distribution filler).  ``pos_offset`` (B,) is each
-    row's absolute start position — the number of prompt tokens already
-    consumed — and must equal the row's current attention-cache length
-    (the caller tracks both; they advance in lockstep).
+    ``tokens`` is a (R, C) slab — C = the engine's ``prefill_chunk`` — so
+    every in-flight prefill advances in ONE dispatch.  ``rows`` (R,) int32
+    names the cache row (slot) of each slab row; None means the slab covers
+    every row of the caches in order (R = B).  A ``rows`` entry >= B is a
+    padding row: it reads an unmapped table and length 0, writes nothing,
+    and its length scatter drops — never repeat a real row's index there,
+    or a stale length could overwrite the real one.  ``valid_len`` (R,)
+    int32 in [0, C] is each slab row's real token count this chunk (0 = no
+    prefill work; the row is in-distribution filler).  ``pos_offset`` (R,)
+    is each row's absolute start position — the number of prompt tokens
+    already consumed — and must equal the row's current attention-cache
+    length (the caller tracks both; they advance in lockstep).
 
     Each row's valid tokens are appended to its cache at
-    ``pos_offset[b]..`` and attend causally to the row's full history;
+    ``pos_offset[j]..`` and attend causally to the row's full history;
     pad positions and inactive rows write nothing (``chunk_into_cache``
     drops their scatter indices) and their outputs are garbage the caller
-    ignores.  Returns (logits (B, V) at each row's LAST VALID chunk
-    position — the next-token logits for rows whose prompt completes this
-    chunk — updated caches, routing stats).  Attention mixers only, like
+    ignores.  Rows are independent, so a row's result does not depend on
+    which other rows share its slab (under an exact FFF dispatch).  Returns
+    (logits (R, V) at each slab row's LAST VALID chunk position — the
+    next-token logits for rows whose prompt completes this chunk — updated
+    caches, routing stats per slab row).  Attention mixers only, like
     ``prefill_padded``."""
+    if rows is not None:
+        view = _rows_view(caches, rows)
+        logits, view, aux = prefill_chunk(params, cfg, tokens, valid_len,
+                                          view, pos_offset)
+        return logits, _rows_merge(caches, view, rows), aux
     x = _embed_inputs(params, cfg, {"tokens": tokens}, pos_offset=pos_offset)
     x, caches, aux = transformer.stack_forward(
         params["stack"], cfg, x, mode="chunk", caches=caches,
@@ -343,6 +355,38 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
     last = jnp.take_along_axis(x, last_idx, axis=1)               # (B, 1, D)
     logits = _head(params, cfg, last)
     return logits[:, 0], caches, aux.get("routing")
+
+
+def _rows_view(caches: list[dict], rows: jax.Array) -> list[dict]:
+    """The pooled caches as seen by the slab rows ``rows`` (R,): the page
+    pools whole and shared, each layer's table and length gathered at
+    ``rows`` (leaves stacked ``(n_periods, B, ...)``).  Rows >= B read an
+    all-sentinel table and length 0."""
+    out = []
+    for c in caches:
+        c = dict(c)
+        kv = c["kv"]
+        c["kv"] = kv._replace(
+            table=kv.table.at[:, rows].get(mode="fill",
+                                           fill_value=kv.k.shape[1]),
+            length=kv.length.at[:, rows].get(mode="fill", fill_value=0))
+        out.append(c)
+    return out
+
+
+def _rows_merge(caches: list[dict], view: list[dict], rows: jax.Array
+                ) -> list[dict]:
+    """Fold a ``_rows_view`` back: the view's page pools replace the
+    caches', and its lengths scatter to ``rows`` (rows >= B drop)."""
+    out = []
+    for c, v in zip(caches, view):
+        c = dict(c)
+        kv, kv_rows = c["kv"], v["kv"]
+        c["kv"] = kv._replace(
+            k=kv_rows.k, v=kv_rows.v,
+            length=kv.length.at[:, rows].set(kv_rows.length, mode="drop"))
+        out.append(c)
+    return out
 
 
 def verify_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,

@@ -22,12 +22,15 @@ interleaved prefill/decode over FIXED compiled shapes:
   *buckets* (powers of two up to ``max_prompt_len``), each bucket compiling
   once; only the admitted row of the ``(num_slots, bucket)`` slab is valid.
 * With ``EngineConfig.prefill_chunk > 0`` prefill is CHUNKED instead: every
-  in-flight prefill advances together through one fixed
-  ``(num_slots, prefill_chunk)`` slab per dispatch (``lm.prefill_chunk`` —
-  one more compiled shape, total), at most ``prefill_budget`` dispatches per
-  engine step, interleaved with decode — a long prompt's admission never
-  stalls in-flight decode latency by more than the budgeted chunk work
-  (DESIGN.md §9).  Decode steps mask cache writes for mid-prefill slots.
+  in-flight prefill advances together through one ``(R, prefill_chunk)``
+  slab per dispatch (``lm.prefill_chunk``), where R is the smallest power
+  of two (capped at ``num_slots``) that holds the prefilling slots — one
+  compiled shape per row bucket, all compiled at the first slab — at most
+  ``prefill_budget`` dispatches per engine step, interleaved with decode —
+  a long prompt's admission never stalls in-flight decode latency by more
+  than the budgeted chunk work (DESIGN.md §9).  Under a ``drop`` /
+  ``master_leaf`` overflow policy the slab stays ``(num_slots,
+  prefill_chunk)``.  Decode steps mask cache writes for mid-prefill slots.
 * Requests enter with prompt + sampling/stop params, decode together until
   EOS/max-tokens, then free their slot for waiting requests (their pages'
   refcounts drop; pages the prefix index still holds stay warm for future
@@ -158,13 +161,14 @@ class EngineConfig:
     ``prefill_chunk``: 0 = monolithic prefill (one bucket-padded dispatch
     per admission, between decode steps); > 0 = chunked prefill — prompts
     advance ``prefill_chunk`` tokens at a time through a shared
-    ``(num_slots, prefill_chunk)`` slab, at most ``prefill_budget`` slab
-    dispatches per engine step.  Must be a power of two <= max_prompt_len
-    (the slab is one fixed compiled shape from the same pow2 family as the
-    buckets).  Smaller chunks / budget bound each step's admission work
-    tighter (decode p99) at the cost of slower admission (TTFT); the
-    scheduler-side ``max_prefilling`` knob caps how many slots prefill
-    concurrently (see serving/scheduler.py)."""
+    ``(R, prefill_chunk)`` slab of the prefilling slots (R a row bucket, at
+    most ``num_slots``), at most ``prefill_budget`` slab dispatches per
+    engine step.  Must be a power of two <= max_prompt_len (the slab's
+    width comes from the same pow2 family as the buckets).  Smaller chunks
+    / budget bound each step's admission work tighter (decode p99) at the
+    cost of slower admission (TTFT); the scheduler-side ``max_prefilling``
+    knob caps how many slots prefill concurrently (see
+    serving/scheduler.py)."""
     num_slots: int = 8
     max_len: int = 128
     max_prompt_len: int = 64
@@ -283,8 +287,8 @@ class ContinuousBatchingEngine:
             if c < 1 or (c & (c - 1)):
                 raise ValueError(
                     f"prefill_chunk {c} must be a power of two — the chunk "
-                    f"slab is one fixed compiled shape from the same pow2 "
-                    f"family as the prefill buckets (DESIGN.md §9)")
+                    f"slab's width comes from the same pow2 family as the "
+                    f"prefill buckets (DESIGN.md §9)")
             if c > ecfg.max_prompt_len:
                 raise ValueError(
                     f"prefill_chunk {c} exceeds max_prompt_len "
@@ -460,13 +464,13 @@ class ContinuousBatchingEngine:
             # slab at bucket width: only the admitted row is valid, and its
             # offset starts at the shared-prefix boundary (DESIGN.md §11).
             def chunk_both():          # a fresh function per compiled shape
-                def prefill_chunk_both(p, dp, t, v, c, dc, off):
+                def prefill_chunk_both(p, dp, t, v, c, dc, off, rows=None):
                     return spec_lib.chunk_both(p, cfg, dp, dcfg, t, v, c, dc,
-                                               off)
+                                               off, rows)
                 return self._jit(prefill_chunk_both, (4, 5),
                                  (None, "target", "draft", None, None))
             self._prefill_jits = {b: chunk_both() for b in ecfg.buckets()}
-            self._chunk_jit = chunk_both() if ecfg.prefill_chunk else None
+            self._chunk_factory = chunk_both
             def cache_admit_both(c, dc, ad, tb, ln, cs, cd):
                 return (lm.cache_admit(c, ad, tb, ln, cs, cd),
                         lm.cache_admit(dc, ad, tb, ln, cs, cd))
@@ -495,16 +499,20 @@ class ContinuousBatchingEngine:
                 (None, None, None, "target", "draft", None, None))
         else:
             def chunk():               # a fresh function per compiled shape
-                def prefill_chunk(p, t, v, c, off):
-                    return lm.prefill_chunk(p, cfg, t, v, c, off)
+                def prefill_chunk(p, t, v, c, off, rows=None):
+                    return lm.prefill_chunk(p, cfg, t, v, c, off, rows)
                 return self._jit(prefill_chunk, (3,), (None, "target", None))
             self._prefill_jits = {b: chunk() for b in ecfg.buckets()}
-            self._chunk_jit = chunk() if ecfg.prefill_chunk else None
+            self._chunk_factory = chunk
 
             def cache_admit(c, ad, tb, ln, cs, cd):
                 return lm.cache_admit(c, ad, tb, ln, cs, cd)
 
             self._admit_jit = self._jit(cache_admit, (0,), "target")
+        # chunked prefill's slab jits, one per row bucket R (the pow2
+        # family up to num_slots), all made at the first slab
+        self._chunk_rows = _pow2_buckets(1, S)
+        self._chunk_jits: Dict[int, Callable] = {}
         # per-slot raw leaf counts accumulated across a request's prefill
         # chunks; normalized into self.occupancy when its prefill completes
         self._prefill_counts = np.zeros((S, max(self.num_leaves, 1)),
@@ -1126,10 +1134,54 @@ class ContinuousBatchingEngine:
         self._measured[slot] = False
         self._seed_hint(slot, req)     # prior until measured counts land
 
+    def _compact_slab(self) -> bool:
+        """Whether the chunk slab holds only the prefilling slots: where the
+        live FFF dispatch is exact for any token count (no capacity bound,
+        or the ``exact_dense`` repair).  Under ``drop`` / ``master_leaf``
+        per-leaf capacity scales with the slab's token count, so a smaller
+        slab would serve other tokens: there the slab stays every slot in
+        order, ``(num_slots, prefill_chunk)``."""
+        return self._overflow_policy() in (None, "exact_dense")
+
+    def _slab_rows(self, prefilling: List[int]) -> np.ndarray:
+        """The chunk slab's rows: the slot of each slab row.  Compacted,
+        the prefilling slots in the smallest row bucket R that holds them,
+        padding rows carrying the out-of-range slot ``num_slots``; else
+        every slot in order."""
+        S = self.ecfg.num_slots
+        if not self._compact_slab():
+            return np.arange(S, dtype=np.int32)
+        R = next(b for b in self._chunk_rows if b >= len(prefilling))
+        rows = np.full((R,), S, np.int32)
+        rows[:len(prefilling)] = prefilling
+        return rows
+
+    def _compile_chunk_family(self) -> None:
+        """Compile the slab program of every row bucket the slab can take,
+        running each once on padding rows alone, which write nothing: every
+        later slab then reuses a compiled shape, however many slots prefill
+        (DESIGN.md §9)."""
+        S, C = self.ecfg.num_slots, self.ecfg.prefill_chunk
+        for R in (self._chunk_rows if self._compact_slab() else (S,)):
+            fn = self._chunk_jits[R] = self._chunk_factory()
+            toks = jnp.full((R, C), int(self._free_tok[-1]), jnp.int32)
+            zeros = jnp.zeros((R,), jnp.int32)
+            rows = jnp.full((R,), S, jnp.int32)
+            with self._ctx():
+                if self.spec:
+                    _, self.caches, self.draft_caches, _, _ = fn(
+                        self.params, self.draft_params, toks, zeros,
+                        self.caches, self.draft_caches, zeros, rows)
+                else:
+                    _, self.caches, _ = fn(self.params, toks, zeros,
+                                           self.caches, zeros, rows)
+
     def _chunk_prefill(self) -> None:
-        """One (num_slots, prefill_chunk) slab dispatch: every mid-prefill
-        slot consumes its next chunk of prompt; rows whose prompt completes
-        sample their first token from the slab's logits (DESIGN.md §9)."""
+        """One chunk slab dispatch: every mid-prefill slot consumes its next
+        chunk of prompt; rows whose prompt completes sample their first
+        token from the slab's logits (DESIGN.md §9).  The slab is
+        ``(R, prefill_chunk)`` over the rows ``_slab_rows`` picks, one
+        compiled shape per row bucket, all compiled at the first slab."""
         prefilling = [i for i, s in enumerate(self.slots)
                       if s is not None and s.prefilling]
         if not prefilling:
@@ -1137,32 +1189,42 @@ class ContinuousBatchingEngine:
         sp = tracing.CHUNK
         with tracing.span(sp.name):
             S, C = self.ecfg.num_slots, self.ecfg.prefill_chunk
-            # inactive rows carry in-distribution filler (same rationale as
-            # the free-slot decode token); valid=0 masks their writes out
-            toks = np.repeat(self._free_tok[:, None], C, axis=1)
-            valid = np.zeros((S,), np.int32)
-            offs = np.zeros((S,), np.int32)
-            for i in prefilling:
+            rows = self._slab_rows(prefilling)
+            R = len(rows)
+            # slab row j feeds slot rows[j]; the (row, slot) pairs with work
+            work = [(j, int(i)) for j, i in enumerate(rows)
+                    if i in prefilling]
+            # rows without work carry in-distribution filler (same
+            # rationale as the free-slot decode token); valid=0 masks their
+            # writes out
+            toks = np.repeat(self._free_tok[np.minimum(rows, S - 1), None],
+                             C, axis=1)
+            valid = np.zeros((R,), np.int32)
+            offs = np.zeros((R,), np.int32)
+            for j, i in work:
                 st = self.slots[i]
                 p = st.request.prompt
                 n = min(C, len(p) - st.prefill_pos)
-                toks[i, :n] = p[st.prefill_pos:st.prefill_pos + n]
-                toks[i, n:] = p[st.prefill_pos + n - 1]   # pad: last real
-                valid[i] = n
-                offs[i] = st.prefill_pos
+                toks[j, :n] = p[st.prefill_pos:st.prefill_pos + n]
+                toks[j, n:] = p[st.prefill_pos + n - 1]   # pad: last real
+                valid[j] = n
+                offs[j] = st.prefill_pos
+            if not self._chunk_jits:
+                self._compile_chunk_family()
+            fn = self._chunk_jits[R]
+            tracing.record(self.now(), self._trace_id, tracing.SLAB_ROWS, R)
             with tracing.span(sp.dispatch), self._ctx():
                 if self.spec:
                     # one slab dispatch advances every prefill in BOTH trees
                     logits, self.caches, self.draft_caches, stats, dstats = \
-                        self._chunk_jit(
-                            self.params, self.draft_params,
-                            jnp.asarray(toks), jnp.asarray(valid),
-                            self.caches, self.draft_caches,
-                            jnp.asarray(offs))
+                        fn(self.params, self.draft_params,
+                           jnp.asarray(toks), jnp.asarray(valid),
+                           self.caches, self.draft_caches,
+                           jnp.asarray(offs), jnp.asarray(rows))
                 else:
-                    logits, self.caches, stats = self._chunk_jit(
+                    logits, self.caches, stats = fn(
                         self.params, jnp.asarray(toks), jnp.asarray(valid),
-                        self.caches, jnp.asarray(offs))
+                        self.caches, jnp.asarray(offs), jnp.asarray(rows))
             with tracing.span(sp.wait):
                 jax.block_until_ready(logits)
             with tracing.span(sp.to_host):
@@ -1176,15 +1238,15 @@ class ContinuousBatchingEngine:
                 # leaf, so RoutingStats.slots counts only valid prompt tokens
                 counts = self._stats_rows(stats, "prefill")
             with tracing.span(sp.sample):
-                for i in prefilling:
+                for j, i in work:
                     st = self.slots[i]
-                    st.prefill_pos += int(valid[i])
-                    self.n_prefill_tokens += int(valid[i])
+                    st.prefill_pos += int(valid[j])
+                    self.n_prefill_tokens += int(valid[j])
                     if self.spec:
-                        self._tlen[i] += int(valid[i])
-                        self._dlen[i] += int(valid[i])
+                        self._tlen[i] += int(valid[j])
+                        self._dlen[i] += int(valid[j])
                     if counts is not None:
-                        self._prefill_counts[i] += counts[i]
+                        self._prefill_counts[i] += counts[j]
                     if not st.prefilling:    # prompt fully consumed
                         self.n_prefills += 1
                         tot = self._prefill_counts[i].sum()
@@ -1193,7 +1255,7 @@ class ContinuousBatchingEngine:
                             self._measured[i] = True
                         st.total_len = len(st.request.prompt)
                         st.first_token_time = self.now()
-                        self._record_token(st, self._sample(st, logits[i]))
+                        self._record_token(st, self._sample(st, logits[j]))
                         self._publish_prefix(i)
 
     def _decode(self) -> None:
@@ -1551,14 +1613,15 @@ class ContinuousBatchingEngine:
     def compiled_shapes(self) -> Dict[str, int]:
         """Number of compiled traces per entry point (the fixed-shape
         contract: after warmup, decode == 1, each prefill bucket <= 1, and
-        the chunk slab — when chunked prefill is on — exactly 1)."""
+        — once chunked prefill has run its first slab — exactly 1 for each
+        chunk row bucket R, reported as ``prefill_chunk_<R>``)."""
         def n(fn):
             return int(fn._cache_size())
         out = {"decode": n(self._decode_jit), "admit": n(self._admit_jit)}
         for b, fn in self._prefill_jits.items():
             out[f"prefill_{b}"] = n(fn)
-        if self._chunk_jit is not None:
-            out["prefill_chunk"] = n(self._chunk_jit)
+        for r, fn in sorted(self._chunk_jits.items()):
+            out[f"prefill_chunk_{r}"] = n(fn)
         if self.spec:
             out["spec_round"] = n(self._spec_jit)
         install = getattr(self, "_cluster_install_jit", None)

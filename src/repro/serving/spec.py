@@ -253,13 +253,16 @@ def spec_round(params: Params, cfg, draft_params: Params, dcfg,
 
 def chunk_both(params: Params, cfg, draft_params: Params, dcfg,
                tokens: jax.Array, valid_len: jax.Array, caches: list,
-               draft_caches: list, pos_offset: jax.Array):
+               draft_caches: list, pos_offset: jax.Array,
+               rows: Optional[jax.Array] = None):
     """Chunked prefill with speculation on: one slab dispatch advances every
-    in-flight prefill through BOTH cache trees."""
+    in-flight prefill through BOTH cache trees, the slab's ``rows`` the same
+    slots of each (``lm.prefill_chunk``)."""
     logits, caches, stats = lm.prefill_chunk(
-        params, cfg, tokens, valid_len, caches, pos_offset)
+        params, cfg, tokens, valid_len, caches, pos_offset, rows)
     _, draft_caches, dstats = lm.prefill_chunk(
-        draft_params, dcfg, tokens, valid_len, draft_caches, pos_offset)
+        draft_params, dcfg, tokens, valid_len, draft_caches, pos_offset,
+        rows)
     return logits, caches, draft_caches, stats, dstats
 
 

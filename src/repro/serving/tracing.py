@@ -24,8 +24,9 @@ where ``<phase>`` is ``serve.chunk``, ``serve.decode`` or ``serve.spec``
 
 Counters are kept by the program whether or not a profiler runs: a bounded
 module-level log of ``(engine-clock time, engine id, name, value)``
-samples, one per counter per step, read back with ``samples``.  The log is
-module-level so that a reader still finds it after the engine is gone.
+samples, one per counter per step or per dispatch, read back with
+``samples``.  The log is module-level so that a reader still finds it
+after the engine is gone.
 """
 from __future__ import annotations
 
@@ -62,6 +63,8 @@ ALL_SPANS = (STEP, EVICT, ADMIT) + CHUNK + DECODE + SPEC
 #: distinct leaves read per FFF site in one decode call (mean over sites);
 #: one sample per decode step or speculative round
 DECODE_LEAVES = "fff.decode_leaves"
+#: rows of one chunked-prefill slab (the row bucket R); one sample per slab
+SLAB_ROWS = "prefill.slab_rows"
 #: traces beyond each entry point's first, summed over entry points, in
 #: one step (a recompile inside serving); one sample per step
 RECOMPILES = "engine.recompiles"

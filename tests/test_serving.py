@@ -338,6 +338,9 @@ def test_engine_e2e_model_parallel_grouped_ep():
                     "--model-parallel", "4", "--prefill-chunk", "8"]
         from repro.launch import serve
         serve.main()
+        from repro.serving import tracing
+        rows = tracing.samples(tracing.SLAB_ROWS, 0.0, float("inf"))
+        print("slab rows:", sorted({int(r) for r in rows}))
     """)
     out = run_with_fake_devices(code)
     assert "expert-parallel serving" in out
@@ -345,8 +348,13 @@ def test_engine_e2e_model_parallel_grouped_ep():
     assert "decode step" in out
     shapes = ast.literal_eval(
         out.split("compiled shapes: ", 1)[1].splitlines()[0])
-    assert (shapes["decode"], shapes["admit"], shapes["prefill_chunk"]) == \
-        (1, 1, 1), shapes
+    reached = ast.literal_eval(out.split("slab rows: ", 1)[1].splitlines()[0])
+    family = {f"prefill_chunk_{r}": 1 for r in (1, 2, 4)}    # --batch 4
+    assert (shapes["decode"], shapes["admit"]) == (1, 1), shapes
+    assert {k: v for k, v in shapes.items()
+            if k.startswith("prefill_chunk_")} == family, shapes
+    assert reached and {f"prefill_chunk_{r}" for r in reached} <= \
+        set(family), reached
 
 
 def test_serve_cli_no_reduced_means_published_widths():

@@ -8,9 +8,15 @@
 * no decode starvation: short requests keep producing tokens while a
   continuous stream of long prompts is admitted;
 * the fixed-compiled-shape bound: chunked serving compiles exactly one
-  decode shape and one chunk-slab shape, whatever the workload mix;
+  decode shape and one shape per chunk row bucket, whatever the workload
+  mix;
+* row compaction: a slab of only the prefilling rows matches the full
+  ``(num_slots, C)`` slab on those rows and leaves every other row
+  bit-identical;
 * engine-level token parity with ``lm.generate``.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,7 +26,7 @@ from repro.configs import registry
 from repro.core import api
 from repro.models import lm
 from repro.serving import (ContinuousBatchingEngine, EngineConfig, Request,
-                           make_scheduler)
+                           make_scheduler, tracing)
 from repro.serving.scheduler import SchedulerView
 
 
@@ -153,6 +159,135 @@ def test_inactive_rows_untouched(model):
 
 
 # ---------------------------------------------------------------------------
+# row compaction: a slab of only the prefilling rows
+# ---------------------------------------------------------------------------
+
+def _pool_pages(caches, table_row):
+    """Every layer's K and V pages of one cache row's page table."""
+    return [np.asarray(a)[:, table_row] for c in caches
+            for a in (c["kv"].k, c["kv"].v)]
+
+
+@pytest.mark.parametrize("slots", [[2], [3, 1], [0, 2, 3], [1, 0, 3, 2]],
+                         ids=["1row", "2rows", "3rows", "num_slots"])
+def test_compacted_slab_matches_full_slab(model, slots):
+    """``lm.prefill_chunk`` over a slab of only the prefilling rows (in any
+    order, padded with out-of-range rows to a power of two) matches the full
+    ``(num_slots, C)`` slab: the same logits on those rows, the same pages
+    and lengths for them, and every other row's pages and length — and
+    every page no row owns, which a padding row could hit — bit-identical."""
+    cfg, params = model
+    B, C, max_len, page = 4, 8, 32, 8
+    rng = np.random.default_rng(11)
+    caches = lm.init_caches(cfg, B, max_len, page_size=page,
+                            num_pages=B * (max_len // page) + 3)
+    # a history of different lengths in every row
+    hist = np.asarray([5, 8, 3, 6], np.int32)
+    _, caches, _ = lm.prefill_chunk(
+        params, cfg, jnp.asarray(rng.integers(1, 256, (B, C)), jnp.int32),
+        jnp.asarray(hist), caches, jnp.zeros((B,), jnp.int32))
+
+    n = {i: int(v) for i, v in zip(slots, rng.integers(1, C + 1, B))}
+    toks = rng.integers(1, 256, (B, C)).astype(np.int32)
+    valid = np.zeros((B,), np.int32)
+    offs = np.zeros((B,), np.int32)
+    for i in slots:
+        valid[i], offs[i] = n[i], hist[i]
+    lg_full, full, _ = jax.jit(
+        lambda c: lm.prefill_chunk(params, cfg, jnp.asarray(toks),
+                                   jnp.asarray(valid), c, jnp.asarray(offs))
+    )(caches)
+
+    R = 1 << (len(slots) - 1).bit_length()
+    rows = np.full((R,), B, np.int32)                   # padding: row B
+    rows[:len(slots)] = slots
+    pick = np.minimum(rows, B - 1)
+    lg_rows, compact, _ = jax.jit(
+        lambda c: lm.prefill_chunk(
+            params, cfg, jnp.asarray(toks[pick]),
+            jnp.asarray(np.where(rows < B, valid[pick], 0)), c,
+            jnp.asarray(offs[pick]), jnp.asarray(rows))
+    )(caches)
+
+    assert np.asarray(lg_rows).shape == (R, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(lg_rows)[:len(slots)],
+                               np.asarray(lg_full)[slots], rtol=1e-5,
+                               atol=1e-5)
+    table = np.asarray(caches[0]["kv"].table)[0]
+    for i in range(B):
+        got, want, before = (_pool_pages(c, table[i])
+                             for c in (compact, full, caches))
+        for g, w, b in zip(got, want, before):
+            if i in slots:
+                np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+            else:
+                np.testing.assert_array_equal(g, w)
+                np.testing.assert_array_equal(g, b)
+    spare = np.setdiff1d(np.arange(caches[0]["kv"].k.shape[1]), table)
+    assert spare.size
+    for g, b in zip(_pool_pages(compact, spare), _pool_pages(caches, spare)):
+        np.testing.assert_array_equal(g, b)
+    for c, f in zip(compact, full):
+        np.testing.assert_array_equal(np.asarray(c["kv"].length),
+                                      np.asarray(f["kv"].length))
+        np.testing.assert_array_equal(np.asarray(c["kv"].table),
+                                      np.asarray(f["kv"].table))
+    want_len = hist + valid
+    np.testing.assert_array_equal(np.asarray(compact[0]["kv"].length)[0],
+                                  want_len)
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "spec"])
+def test_engine_three_of_four_slots_prefilling_matches_generate(model,
+                                                                spec_k):
+    """Three prompts admitted in one step prefill together through a
+    4-row slab with one padding row; every greedy token equals
+    ``lm.generate``'s, with speculation off and on."""
+    cfg, params = model
+    eng = _engine(cfg, params, max_prefills_per_step=3, spec_k=spec_k)
+    reached = _track_slab_rows(eng)
+    rng = np.random.default_rng(12)
+    reqs = [Request(rid=i, prompt=rng.integers(1, 256, int(L)),
+                    max_new_tokens=5)
+            for i, L in enumerate((40, 33, 50))]
+    results, m = eng.run(reqs)
+    assert reached.n_max == 3 and 4 in reached
+    for r in results:
+        want = lm.generate(params, cfg, jnp.asarray(r.prompt[None]),
+                           steps=r.n_generated, max_len=80)
+        np.testing.assert_array_equal(
+            np.asarray(want)[0], np.concatenate([r.prompt, r.tokens]),
+            err_msg=f"rid {r.rid}")
+
+
+@pytest.mark.parametrize("policy,compact",
+                         [("drop", False), ("master_leaf", False),
+                          ("exact_dense", True)])
+def test_slab_rows_follow_the_overflow_policy(model, policy, compact):
+    """Under a capacity-bounded dispatch that drops (``drop``) or stands in
+    (``master_leaf``) per-leaf capacity scales with the slab, so the slab
+    stays ``(num_slots, C)``; under the exact repair it holds only the
+    prefilling rows.  The counter ``prefill.slab_rows`` reads the rows of
+    every slab."""
+    cfg, _ = model
+    cfg = dataclasses.replace(cfg, period=tuple(
+        dataclasses.replace(b, ffn=dataclasses.replace(
+            b.ffn, fff_master_leaf=True)) for b in cfg.period))
+    params = lm.init(jax.random.PRNGKey(0), cfg)
+    eng = _engine(cfg, params, fff_backend="grouped", overflow_policy=policy)
+    reached = _track_slab_rows(eng)
+    rng = np.random.default_rng(13)
+    eng.run([Request(rid=i, prompt=rng.integers(1, 256, 40),
+                     max_new_tokens=2) for i in range(2)])
+    assert reached == {2} and reached.n_max == 2
+    family = {1, 2, 4} if compact else {4}
+    assert _chunk_buckets(eng.compiled_shapes()) == {r: 1 for r in family}
+    got = tracing.samples(tracing.SLAB_ROWS, 0.0, float("inf"),
+                          engine=eng._trace_id)
+    assert got and set(got) == ({2.0} if compact else {4.0})
+
+
+# ---------------------------------------------------------------------------
 # engine-level invariants
 # ---------------------------------------------------------------------------
 
@@ -185,21 +320,53 @@ def test_chunked_engine_matches_lm_generate(model):
             err_msg=f"rid {r.rid}")
 
 
+class _Reached(set):
+    """Row buckets reached, and the most slots that prefilled at once."""
+    n_max = 0
+
+
+def _track_slab_rows(eng) -> _Reached:
+    """The chunk row buckets ``eng``'s slabs need from now on, worked out
+    from the prefilling slots before each slab dispatch (the next power of
+    two, capped at num_slots) — independently of the engine's own bucket
+    choice."""
+    reached = _Reached()
+    inner = eng._chunk_prefill
+
+    def chunk_prefill():
+        n = sum(s is not None and s.prefilling for s in eng.slots)
+        if n:
+            reached.add(min(1 << (n - 1).bit_length(), eng.ecfg.num_slots))
+            reached.n_max = max(reached.n_max, n)
+        inner()
+
+    eng._chunk_prefill = chunk_prefill
+    return reached
+
+
+def _chunk_buckets(shapes) -> dict:
+    """Row bucket -> compiled traces, from ``compiled_shapes``."""
+    return {int(k.rsplit("_", 1)[1]): v for k, v in shapes.items()
+            if k.startswith("prefill_chunk_")}
+
+
 def test_chunked_fixed_compiled_shapes(model):
-    """Chunked serving compiles ONE decode shape, ONE chunk-slab shape and
-    ZERO prefill buckets, whatever the prompt-length mix — tighter than the
-    monolithic per-bucket bound."""
+    """Chunked serving compiles ONE decode shape, each chunk row bucket
+    exactly once (all at the first slab) and ZERO prefill buckets, whatever
+    the prompt-length mix — tighter than the monolithic per-bucket bound."""
     cfg, params = model
     eng = _engine(cfg, params)
+    reached = _track_slab_rows(eng)
     eng.run(_mixed_requests(6, np.random.default_rng(4)))
     warm = eng.compiled_shapes()
     eng.run(_mixed_requests(8, np.random.default_rng(5)))
     after = eng.compiled_shapes()
     assert after == warm, "recompilation after warmup"
     assert after["decode"] == 1
-    assert after["prefill_chunk"] == 1
+    assert _chunk_buckets(after) == {1: 1, 2: 1, 4: 1}
+    assert reached and reached <= set(_chunk_buckets(after))
     assert all(v == 0 for k, v in after.items() if k.startswith("prefill_")
-               and k != "prefill_chunk")
+               and not k.startswith("prefill_chunk_"))
 
 
 def test_no_decode_starvation_under_long_prompt_stream(model):
@@ -270,19 +437,21 @@ def test_scheduler_admission_cap_math():
 
 def test_compile_bound(model):
     """The documented compile contract, standalone: chunked serving runs on
-    EXACTLY decode 1 + chunk slab 1 + admit 1 compiled traces with zero
-    bucket prefills (docs/serving.md).  The CI serving job runs this single
-    node id as a dedicated gate step, so a contract regression fails loudly
-    on its own instead of somewhere inside the full suite."""
+    EXACTLY decode 1 + admit 1 + each chunk row bucket 1 compiled traces
+    with zero bucket prefills (docs/serving.md).  The CI
+    serving job runs this single node id as a dedicated gate step, so a
+    contract regression fails loudly on its own instead of somewhere inside
+    the full suite."""
     cfg, params = model
     eng = _engine(cfg, params)
     eng.run(_mixed_requests(5, np.random.default_rng(9)))
     shapes = eng.compiled_shapes()
     assert shapes["decode"] == 1, shapes
-    assert shapes["prefill_chunk"] == 1, shapes
+    assert _chunk_buckets(shapes) == {1: 1, 2: 1, 4: 1}, shapes
     assert shapes["admit"] == 1, shapes
     assert all(v == 0 for k, v in shapes.items()
-               if k.startswith("prefill_") and k != "prefill_chunk"), shapes
+               if k.startswith("prefill_")
+               and not k.startswith("prefill_chunk_")), shapes
 
 
 def test_chunk_config_validation(model):

@@ -31,7 +31,8 @@ from repro.cluster.placement import overlap
 from repro.configs import registry
 from repro.distributed import StragglerConfig
 from repro.models import lm
-from repro.serving import ContinuousBatchingEngine, EngineConfig, Request
+from repro.serving import (ContinuousBatchingEngine, EngineConfig, Request,
+                           tracing)
 from repro.serving.engine import VirtualClock
 
 # ---------------------------------------------------------------------------
@@ -348,8 +349,16 @@ def test_cluster_kill_decode_worker_exact_replay(model):
         assert list(r.tokens) == list(np.asarray(want))
     # the killed worker is gone; its replacement carries a fresh wid
     assert "d0" not in router.views and "d2" in router.views
-    # chunked prefill keeps the slab ledger at 1 on the prefill worker
-    assert engines["p0"].compiled_shapes()["prefill_chunk"] == 1
+    # chunked prefill keeps the slab ledger at 1 per row bucket on the
+    # prefill worker, and its slabs used only those buckets
+    p0 = engines["p0"]
+    family = {"prefill_chunk_1": 1, "prefill_chunk_2": 1}   # 2 slots
+    assert {k: v for k, v in p0.compiled_shapes().items()
+            if k.startswith("prefill_chunk_")} == family
+    reached = tracing.samples(tracing.SLAB_ROWS, 0.0, float("inf"),
+                              engine=p0._trace_id)
+    assert reached and {f"prefill_chunk_{int(r)}" for r in reached} <= \
+        set(family)
 
 
 def test_cluster_drain_blocks_new_admissions(model):
