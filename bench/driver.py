@@ -70,7 +70,10 @@ class Window:
     shapes_after: dict
 
 
-def build_engine(params, program_cfg, mix: dict, seed: int):
+def build_engine(params, program_cfg, mix: dict, seed: int, mesh=None,
+                 trace_ctx=None):
+    """The engine as the mix configures it; ``mesh`` and ``trace_ctx`` are
+    the serving mesh (``run.serving_mesh``), None on one chip."""
     from repro.serving.engine import ContinuousBatchingEngine, EngineConfig
     e = mix["engine"]
     ecfg = EngineConfig(
@@ -78,7 +81,8 @@ def build_engine(params, program_cfg, mix: dict, seed: int):
         max_prompt_len=int(e["max_prompt_len"]),
         prefill_chunk=int(e["prefill_chunk"]),
         fff_backend=e.get("fff_backend", "auto"), seed=int(seed) % (1 << 31))
-    return ContinuousBatchingEngine(params, program_cfg, ecfg)
+    return ContinuousBatchingEngine(params, program_cfg, ecfg,
+                                    trace_ctx=trace_ctx, mesh=mesh)
 
 
 def warm_up(engine, mix: dict, vocab: int) -> None:

@@ -4,14 +4,16 @@
 
 Reads the cell from ``BENCHMARK.json`` and its configuration and traffic mix
 from ``bench/configs`` and ``bench/traffic``; makes the weights and the
-requests from ``--seed``; builds the serving engine and warms up every
-shape the window uses (set-up); serves the traffic for ``--seconds``
-(the window); drains; reads the device's peak memory; frees the engine;
-then checks the served tokens against the float32 reference
-(``correct.py``).  With ``--trace 0`` the metrics are the cell's end-to-end
-ones, with ``--trace 1`` its per-layer ones, read from a profiler trace of
-the window.  The last line of standard output is one JSON object; the
-numbers compared for ``correct`` close standard error.
+requests from ``--seed``; builds the serving engine (on a cell of several
+chips under the program's serving mesh over them, the weights born sharded)
+and warms up every shape the window uses (set-up); serves the traffic for
+``--seconds`` (the window); drains; reads the peak memory of the fullest
+device; frees the engine; then checks the served tokens against the float32
+reference (``correct.py``).  With ``--trace 0`` the metrics are the cell's
+end-to-end ones, with ``--trace 1`` its per-layer ones, read from a
+profiler trace of the window (no longer than the mix's ``trace_seconds``
+where it sets one).  The last line of standard output is one
+JSON object; the numbers compared for ``correct`` close standard error.
 
 Needs an accelerator: with none, or fewer chips than the cell asks for, it
 exits non-zero and prints no result.
@@ -71,6 +73,39 @@ def start_jax(n: int):
     return devs[:n]
 
 
+def serving_mesh(devices) -> tuple:
+    """``(mesh, trace_ctx)`` the engine serves under: ``(None, None)`` on
+    one chip; on n chips the program's serving layout
+    (``launch/mesh.serving_context(n)``: a ``("data", "model")`` mesh of
+    shape (1, n) whose activation rules every jitted call traces under).
+    That layout takes every visible device, so it has to be the cell's."""
+    if len(devices) == 1:
+        return None, None
+    from repro.launch import mesh as mesh_lib
+    mesh, trace_ctx = mesh_lib.serving_context(len(devices))
+    if dict(mesh.shape) != {"data": 1, "model": len(devices)} or \
+            set(mesh.devices.flat) != set(devices):
+        raise RuntimeError(f"the serving mesh {dict(mesh.shape)} is not a "
+                           f"(1, {len(devices)}) mesh of the cell's chips")
+    return mesh, trace_ctx
+
+
+def window_seconds(mix: dict, seconds: float, traced: bool) -> float:
+    """The window's length: ``--seconds``, but in a traced run no more than
+    the mix's ``trace_seconds`` where it sets one, for a cell whose trace
+    of a whole window takes longer to write and read than a run may last.
+    The per-layer metrics then read that shorter window throughout."""
+    if traced and "trace_seconds" in mix:
+        return min(seconds, float(mix["trace_seconds"]))
+    return seconds
+
+
+def memory_peaks(devices) -> list:
+    """``peak_bytes_in_use`` of each device (0 where none is kept)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
 def serve(cell, program_cfg, peaks: dict, seed: int, seconds: float,
           traced: bool, devices) -> tuple:
     """Set-up, window, drain.  Returns (run record, params, window)."""
@@ -78,9 +113,11 @@ def serve(cell, program_cfg, peaks: dict, seed: int, seconds: float,
     from bench import driver, traffic, trace, weights
 
     config, mix = cell.config, cell.traffic
-    params = jax.block_until_ready(weights.make(config, seed))
+    mesh, trace_ctx = serving_mesh(devices)
+    params = jax.block_until_ready(weights.make(config, seed, mesh))
     weights.check_layout(params, program_cfg)
-    engine = driver.build_engine(params, program_cfg, mix, seed)
+    engine = driver.build_engine(params, program_cfg, mix, seed, mesh,
+                                 trace_ctx)
     counter = driver.CompileCounter()
     driver.warm_up(engine, mix, config["vocab_size"])
     items = traffic.generate(mix, seed, config["vocab_size"], seconds,
@@ -111,9 +148,10 @@ def serve(cell, program_cfg, peaks: dict, seed: int, seconds: float,
         late = sorted(window.late_s)
         log(f"generator lateness: median {late[len(late) // 2] * 1e3:.3f} ms, "
             f"max {late[-1] * 1e3:.3f} ms over {len(late)} arrivals")
-    stats = devices[0].memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
-    log(f"device memory: {stats}")
+    per_device = memory_peaks(devices)
+    peak = max(per_device)
+    log(f"device memory: {devices[0].memory_stats() or {}}")
+    log(f"peak bytes in use per device: {per_device}")
     del engine
     gc.collect()
     reduction = table = None
@@ -124,8 +162,8 @@ def serve(cell, program_cfg, peaks: dict, seed: int, seconds: float,
             table, trace.window_on_profile_clock(table, "bench.window"))
     run = types.SimpleNamespace(
         config=config, mix=mix, window=window, trace=reduction, peaks=peaks,
-        chips=len(devices), setup_s=marks["setup_s"], seconds=seconds,
-        memory_peak_bytes=peak, trace_table=table)
+        chips=len(devices), mesh=mesh, setup_s=marks["setup_s"],
+        seconds=seconds, memory_peak_bytes=peak, trace_table=table)
     return run, params, window
 
 
@@ -147,9 +185,10 @@ def main(argv=None) -> int:
         return 3
 
     from bench import correct
+    seconds = window_seconds(cell.traffic, args.seconds, bool(args.trace))
     run, params, window = serve(
         cell, spec.program_config(cell.config),
-        spec.load_peaks(devices[0].device_kind), args.seed, args.seconds,
+        spec.load_peaks(devices[0].device_kind), args.seed, seconds,
         bool(args.trace), devices)
     verdict = correct.check(
         params, cell.config, window, args.seed,

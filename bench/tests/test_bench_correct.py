@@ -167,3 +167,10 @@ def test_traced_run_reads_the_window_from_the_trace():
     assert bench_run.read_metric("fff_fused_decode_roofline", run) is None
     assert bench_run.read_metric("idle_share.backlog", run) == \
         pytest.approx(100.0)
+
+
+@pytest.mark.parametrize("traced, mix, want", [
+    (False, {}, 45.0), (True, {}, 45.0), (False, {"trace_seconds": 5}, 45.0),
+    (True, {"trace_seconds": 5}, 5.0), (True, {"trace_seconds": 60}, 45.0)])
+def test_trace_seconds_shortens_only_a_traced_window(traced, mix, want):
+    assert bench_run.window_seconds(mix, 45.0, traced) == want

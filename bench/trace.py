@@ -120,6 +120,18 @@ class Reduction:
         return sum(hits) / max(self.devices, 1), len(hits)
 
 
+def busy_by_device(table: dict, window_ns: tuple, keep=None) -> list:
+    """Seconds of each device inside ``window_ns`` in which one of its ops
+    ran (the union of their intervals, whatever nests in what); with
+    ``keep``, only the ops whose name ``keep(name)`` accepts."""
+    w0, w1 = window_ns
+    spans: list = [[] for _ in range(max(table["devices"], 1))]
+    for n, _, s, d, dev in table["ops"]:
+        if s + d > w0 and s < w1 and (keep is None or keep(n)):
+            spans[dev].append((max(s, w0), min(s + d, w1)))
+    return [sum(e - s for s, e in _union(iv)) / 1e9 for iv in spans]
+
+
 def reduce(table: dict, window_ns: tuple) -> Reduction:
     """Busy time, per-op time and the longest idle gaps inside
     ``window_ns = (start, end)`` on the profile's clock."""
@@ -127,11 +139,7 @@ def reduce(table: dict, window_ns: tuple) -> Reduction:
     ops = [(s, s + d, n, t, dev) for n, t, s, d, dev in table["ops"]
            if s + d > w0 and s < w1]
     ndev = max(table["devices"], 1)
-    busy = 0.0
-    for dev in range(ndev):
-        merged = _union([(max(s, w0), min(e, w1))
-                         for s, e, _, _, k in ops if k == dev])
-        busy += sum(e - s for s, e in merged) / 1e9 / ndev
+    busy = sum(busy_by_device(table, window_ns)) / ndev
     # idle gaps of the first device, labelled with what the host was doing
     merged = _union([(max(s, w0), min(e, w1))
                      for s, e, _, _, k in ops if k == 0])

@@ -65,8 +65,12 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-def make(config: dict, seed: int) -> dict:
-    """All parameters, drawn on the default device in one program."""
+def make(config: dict, seed: int, mesh=None) -> dict:
+    """All parameters, drawn in one program: on the default device, or
+    under a serving ``mesh`` each born on its shard as the program places
+    it (``sharding.param_shardings``, as ``lm.init_params`` does), so that
+    no device ever holds the whole model.  The values depend on the seed
+    alone (threefry is partitionable), not on where they are placed."""
     dtype = jnp.dtype(config["dtype"])
     table = shapes(config)
 
@@ -83,7 +87,13 @@ def make(config: dict, seed: int) -> dict:
                     k, -2.0, 2.0, shape, jnp.float32) * how).astype(dtype)
         return _nest(flat)
 
-    return jax.jit(draw)(key_from_seed(seed, stream=1))
+    key = key_from_seed(seed, stream=1)
+    if mesh is None:
+        return jax.jit(draw)(key)
+    from repro.distributed import sharding
+    placed = sharding.param_shardings(jax.eval_shape(draw, key), mesh,
+                                      fsdp=False)
+    return jax.jit(draw, out_shardings=placed)(key)
 
 
 def check_layout(params: dict, program_cfg) -> None:
